@@ -1,9 +1,8 @@
 """Benchmark harness entry point: one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (benchmarks.common.emit).
-The serving suite additionally appends machine-readable records to
-``BENCH_serve.json`` (batch, µs/decode-step, tokens/s, HBM ratios,
-slab-vs-paged concurrency) so the perf trajectory accumulates across runs.
+Speed is measured on the chip by ``bench/run.py``; these suites reproduce
+the paper's tables and figures.
 
     PYTHONPATH=src python -m benchmarks.run [--quick]
 """
@@ -28,24 +27,18 @@ def main() -> None:
     from benchmarks import (
         ablations,
         autoswitch_bench,
-        kernel_bench,
         layerwise,
         recipes,
-        roofline,
-        serve_bench,
         sparsity_sweep,
     )
 
     suites = {
-        "kernels": kernel_bench.run,                       # §Kernels
-        "serve": serve_bench.run,                          # §Serving engine
         "autoswitch": lambda: autoswitch_bench.run(steps=max(300, steps)),  # Table 1
         "recipes": lambda: (recipes.table_mlp(steps=steps, seeds=(0,)),
                             recipes.table_lm(steps=120)),  # Tables 2-3
         "sparsity_sweep": lambda: sparsity_sweep.run(steps=120),            # Fig 5
         "layerwise": lambda: layerwise.run(steps=120),                      # Table 4
         "ablations": ablations.run,                                         # Figs 6-8
-        "roofline": roofline.run,                                           # §Roofline
     }
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")

@@ -181,23 +181,19 @@ class Recipe:
         """The weights fed to the model this step (Eq. 8's Π⊙w, via STE)."""
         if self.kind == "dense":
             return params
-        if self.kind == "asp":
-            # true masked gradient: pruned weights stay dead
+        # ASP: true masked gradient, pruned weights stay dead; STE family:
+        # straight-through, the full gradient reaches the dense weights
+        apply = (
+            masking.masked_no_ste
+            if self.kind == "asp"
+            else masking.straight_through_mask
+        )
+        with jax.named_scope("nm_mask"):
             return jax.tree_util.tree_map(
-                lambda p, mk: masking.masked_no_ste(
-                    p, jnp.where(active, mk, jnp.ones_like(mk))
-                ),
+                lambda p, mk: apply(p, jnp.where(active, mk, jnp.ones_like(mk))),
                 params,
                 mask,
             )
-        # STE family: straight-through — full gradient reaches dense weights
-        return jax.tree_util.tree_map(
-            lambda p, mk: masking.straight_through_mask(
-                p, jnp.where(active, mk, jnp.ones_like(mk))
-            ),
-            params,
-            mask,
-        )
 
     def grad_postprocess(
         self, grads: Any, params: Any, mask: Any, active: jnp.ndarray

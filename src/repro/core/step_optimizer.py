@@ -134,8 +134,11 @@ def step_optimizer(cfg: StepConfig) -> GradientTransformation:
         bc2 = 1 - b2**tf
 
         # --- AutoSwitch sampling (phase-1 signal; harmless but unused in p2)
-        z_t = variance_change_sample(grads, state.v, asw_cfg)
-        asw_state, z_bar, crit = autoswitch_step(state.autoswitch, z_t, t, asw_cfg)
+        with jax.named_scope("autoswitch"):
+            z_t = variance_change_sample(grads, state.v, asw_cfg)
+            asw_state, z_bar, crit = autoswitch_step(
+                state.autoswitch, z_t, t, asw_cfg
+            )
         if cfg.switch_at is not None:
             crit = t >= cfg.switch_at
         switch_now = jnp.logical_and(jnp.logical_not(in_p2), crit)

@@ -5,7 +5,7 @@ defaults straight into the serving matmul and left the CPU production path
 on a ``put_along_axis`` scatter-decompress — running either a Pallas kernel
 under the Python interpreter or an XLA scatter inside the decode hot loop.
 That is how compressed decode measured ~8x *slower* than dense at batch 1
-(``BENCH_serve.json``, PR 2).  Kernel routing belongs in one place, decided
+on the CPU.  Kernel routing belongs in one place, decided
 by backend + shape, never hardcoded at a call site.
 
 Modes

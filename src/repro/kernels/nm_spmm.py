@@ -7,9 +7,8 @@ tiles — values ``(K·N/M, O)`` + 8-bit in-group indices — into VMEM,
 decompresses the tile *inside* VMEM with unrolled select ops, and feeds the
 dense MXU.
 
-Bandwidth model (per weight, measured on the ``gpt2-paper`` smoke artifact
-via ``benchmarks/serve_bench.py`` — see ``weight_bytes_per_step`` in
-``BENCH_serve.json``):
+Bandwidth model (per weight, counted on the ``gpt2-paper`` smoke artifact —
+see ``weight_bytes_per_step`` in the serving engine's stats):
 
     HBM weight traffic per tile:  (N/M)·(bits_w + 8)/bits_w of dense
     (2:4 bf16: 0.75x;  1:4: 0.375x;  2:8 int8 would be 0.5x)
@@ -192,8 +191,8 @@ def nm_spmm_pallas(
 # decompress 4.2ms; the gather scales with rows and loses by ~20x at
 # b=8 on 2048^2, where decompress+BLAS takes over).  Off-TPU the point is
 # bounded damage, not a win: at serving-bench sizes compressed decode now
-# matches-or-beats dense at batch 1 and stays within 2x above
-# (BENCH_serve.json), while at >=1024^2 single-row
+# matches-or-beats dense at batch 1 and stays within 2x above,
+# while at >=1024^2 single-row
 # shapes both formulations pay ~one decompress of traffic vs a GEMV —
 # the bandwidth *win* needs the TPU kernel, which never decompresses to
 # HBM at all.

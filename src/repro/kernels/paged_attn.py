@@ -12,16 +12,14 @@ pages in f32 VMEM scratch.  Sentinel (unmapped) slots clamp their DMA to a
 resident page and skip all compute under ``pl.when``; sliding-window lanes
 visit only slots whose logical page intersects the live window.
 
-Bytes per decode step (the quantity this kernel exists to shrink; measured
-fields ``kv_bytes_per_step`` / ``bytes_read_per_step`` in
-``BENCH_serve.json`` and ``kv_byte_ratio`` in ``BENCH_paged_attn.json``):
+Bytes per decode step (the quantity this kernel exists to shrink; the
+engine's stats report it as ``kv_bytes_per_step`` / ``bytes_read_per_step``):
 the gathered path materializes every lane's full ``S_max`` logical view
 per layer per step; the kernel reads each lane's ``ceil(len/ps)`` live
 pages once.  Measured: the slab-vs-paged serve sweep averages ~24.3 KB of
 live KV per step (up to 5 concurrent heterogeneous lanes) where the
-gathered view is ~328 KB — a 13x byte gap — and the
-``kernel_bench`` paged-attn cases at 12.5–25% occupancy read 0.156x–0.312x
-of the gathered bytes.  The gap widens linearly with ``S_max / len``.
+gathered view is ~328 KB — a 13x byte gap — and at 12.5–25% occupancy
+the kernel reads 0.156x–0.312x of the gathered bytes.  The gap widens linearly with ``S_max / len``.
 
 Operand contract (kernel layout — callers reshape, see
 ``models.cache.PagedLayout.attn_decode`` / ``models.mla.mla_decode``):

@@ -278,8 +278,8 @@ def main(argv=None) -> dict:
         "hbm_weight_ratio": round(rep["ratio"], 3),
         "mesh": engine.mesh_desc(),
         # which paged-attention implementation decode resolved at trace
-        # time ("slab" when no paged kernel is in play) — serve_bench's
-        # sharded sweep compares xla vs shard_map streams on this field
+        # time ("slab" when no paged kernel is in play); a sharded run
+        # compares xla vs shard_map streams on this field
         "kernel_route": engine.kernel_route(),
         # the routes the compressed matmuls took ("none" for --dense)
         "nm_spmm_route": engine.kernel_routes().get("nm_spmm", "none"),

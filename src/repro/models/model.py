@@ -249,9 +249,10 @@ def _block_forward(
     cache = None
     h = _apply_norm(cfg, p["pre"], x)
     if mixer == "attn":
-        mix_out, cache = _attn_forward(
-            h, p["attn"], cfg, positions, chunk=chunk, want_cache=want_cache
-        )
+        with jax.named_scope("attention"):
+            mix_out, cache = _attn_forward(
+                h, p["attn"], cfg, positions, chunk=chunk, want_cache=want_cache
+            )
     elif mixer == "mla":
         pos1d = positions if positions.ndim == 2 else positions[..., 0]
         mix_out, lat = MLA.mla_attention(
@@ -269,13 +270,16 @@ def _block_forward(
     x = x + mix_out
     if mlp != "none":
         h2 = _apply_norm(cfg, p["post"], x)
-        if mlp == "moe":
-            mo, a = MOE.moe_mlp(h2, p["moe"], cfg.moe, ep_constraint=ep_constraint)
-            aux = aux + a
-        elif cfg.mlp == "swiglu":
-            mo = L.swiglu_mlp(h2, p["mlp"])
-        else:
-            mo = L.gelu_mlp(h2, p["mlp"])
+        with jax.named_scope("mlp"):
+            if mlp == "moe":
+                mo, a = MOE.moe_mlp(
+                    h2, p["moe"], cfg.moe, ep_constraint=ep_constraint
+                )
+                aux = aux + a
+            elif cfg.mlp == "swiglu":
+                mo = L.swiglu_mlp(h2, p["mlp"])
+            else:
+                mo = L.gelu_mlp(h2, p["mlp"])
         x = x + mo
     return x, aux, cache
 
@@ -316,7 +320,8 @@ def forward(
     else:
         tokens = batch["tokens"]
         b, s = tokens.shape
-        x = params["embed"]["tok_embed"][tokens]
+        with jax.named_scope("lm_head"):
+            x = params["embed"]["tok_embed"][tokens]
     positions = batch.get("positions")
     if positions is None:
         positions = _default_positions(cfg, b, s)
@@ -326,55 +331,57 @@ def forward(
     aux = jnp.zeros((), jnp.float32)
     caches: dict = {}
 
-    for i, kind in enumerate(plan.head):
-        x, a, c = _block_forward(
-            x, params[f"head_{i}"], kind, cfg, positions,
-            chunk=chunk, want_cache=want_cache, ep_constraint=ep_constraint,
-        )
-        aux += a
-        if want_cache:
-            caches[f"head_{i}"] = c
+    with jax.named_scope("layers"):
+        for i, kind in enumerate(plan.head):
+            x, a, c = _block_forward(
+                x, params[f"head_{i}"], kind, cfg, positions,
+                chunk=chunk, want_cache=want_cache, ep_constraint=ep_constraint,
+            )
+            aux += a
+            if want_cache:
+                caches[f"head_{i}"] = c
 
-    if plan.n_body:
-        def superblock(x, p_sb):
-            a_tot = jnp.zeros((), jnp.float32)
-            cs = {}
-            for j, kind in enumerate(plan.period):
-                x, a, c = _block_forward(
-                    x, p_sb[f"sb_{j}"], kind, cfg, positions,
-                    chunk=chunk, want_cache=want_cache, ep_constraint=ep_constraint,
-                )
-                a_tot += a
-                if want_cache:
-                    cs[f"sb_{j}"] = c
-            if block_constraint is not None:
-                x = block_constraint(x)
-            return x, (a_tot, cs if want_cache else None)
+        if plan.n_body:
+            def superblock(x, p_sb):
+                a_tot = jnp.zeros((), jnp.float32)
+                cs = {}
+                for j, kind in enumerate(plan.period):
+                    x, a, c = _block_forward(
+                        x, p_sb[f"sb_{j}"], kind, cfg, positions,
+                        chunk=chunk, want_cache=want_cache, ep_constraint=ep_constraint,
+                    )
+                    a_tot += a
+                    if want_cache:
+                        cs[f"sb_{j}"] = c
+                if block_constraint is not None:
+                    x = block_constraint(x)
+                return x, (a_tot, cs if want_cache else None)
 
-        sb_fn = jax.checkpoint(superblock) if remat else superblock
+            sb_fn = jax.checkpoint(superblock) if remat else superblock
 
-        def scan_body(x, p_sb):
-            return sb_fn(x, p_sb)
+            def scan_body(x, p_sb):
+                return sb_fn(x, p_sb)
 
-        x, (a_list, c_stack) = jax.lax.scan(scan_body, x, params["body"])
-        aux += jnp.sum(a_list)
-        if want_cache:
-            caches["body"] = c_stack
+            x, (a_list, c_stack) = jax.lax.scan(scan_body, x, params["body"])
+            aux += jnp.sum(a_list)
+            if want_cache:
+                caches["body"] = c_stack
 
-    for i, kind in enumerate(plan.tail):
-        x, a, c = _block_forward(
-            x, params[f"tail_{i}"], kind, cfg, positions,
-            chunk=chunk, want_cache=want_cache, ep_constraint=ep_constraint,
-        )
-        aux += a
-        if want_cache:
-            caches[f"tail_{i}"] = c
+        for i, kind in enumerate(plan.tail):
+            x, a, c = _block_forward(
+                x, params[f"tail_{i}"], kind, cfg, positions,
+                chunk=chunk, want_cache=want_cache, ep_constraint=ep_constraint,
+            )
+            aux += a
+            if want_cache:
+                caches[f"tail_{i}"] = c
 
-    x = _apply_norm(cfg, params["final"], x)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["tok_embed"].T  # embeddings stay dense
-    else:
-        logits = L.matmul(x, params["unembed"]["out_embed"])
+    with jax.named_scope("lm_head"):
+        x = _apply_norm(cfg, params["final"], x)
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"]["tok_embed"].T  # embeddings stay dense
+        else:
+            logits = L.matmul(x, params["unembed"]["out_embed"])
     return logits, aux, (caches if want_cache else None)
 
 
@@ -392,20 +399,21 @@ def loss_fn(
         # all-gathering the (B,S,V) logits — §Perf hillclimb #1)
         logits = logits_constraint(logits)
     labels = batch["labels"]
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    ll = jnp.take_along_axis(lf, labels[..., None], axis=-1)[..., 0]
     mask = batch.get("loss_mask")
-    nll = lse - ll
-    zloss = jnp.square(lse)
-    if mask is not None:
-        denom = jnp.maximum(jnp.sum(mask), 1.0)
-        ce = jnp.sum(nll * mask) / denom
-        zl = jnp.sum(zloss * mask) / denom
-    else:
-        ce = jnp.mean(nll)
-        zl = jnp.mean(zloss)
-    total = ce + aux_weight * aux + z_weight * zl
+    with jax.named_scope("lm_head"):
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        ll = jnp.take_along_axis(lf, labels[..., None], axis=-1)[..., 0]
+        nll = lse - ll
+        zloss = jnp.square(lse)
+        if mask is not None:
+            denom = jnp.maximum(jnp.sum(mask), 1.0)
+            ce = jnp.sum(nll * mask) / denom
+            zl = jnp.sum(zloss * mask) / denom
+        else:
+            ce = jnp.mean(nll)
+            zl = jnp.mean(zloss)
+        total = ce + aux_weight * aux + z_weight * zl
     return total, {"ce": ce, "aux": aux, "zloss": zl}
 
 

@@ -15,8 +15,8 @@ Usage inside a shard_map'd train step::
     grad = jax.lax.pmean(compressed, axis_name)     # tiny payload semantics
 
 On real hardware the payload is packed to int8 words by XLA; in this
-framework the roofline accounting (benchmarks/roofline.py) models the 1-bit
-wire format analytically while the numerics below are exact.
+framework ``compressed_bits_per_element`` models the 1-bit wire format
+analytically while the numerics below are exact.
 """
 from __future__ import annotations
 
@@ -79,5 +79,5 @@ def ef_sign_compress(
 
 
 def compressed_bits_per_element(dtype=jnp.bfloat16) -> float:
-    """Wire-format cost model used by the roofline accounting."""
+    """Wire-format cost model: bits per gradient element on the wire."""
     return 1.0  # 1 bit/elem + negligible per-tensor f32 scale

@@ -2144,8 +2144,7 @@ class DecodeEngine:
         """KV bytes the *paged fast path* reads this step: each active
         lane's live tokens once.  (The gathered reference reads — and
         rewrites — the full ``B × S_max`` view instead; the slab engine
-        has no choice.  This is the bytes-read-per-step roofline input
-        that ``benchmarks/serve_bench.py`` records.)"""
+        has no choice.  This is the bytes-read-per-step roofline input.)"""
         full_b, win_b = self._kv_row_bytes()
         win = (
             min(self.max_len, self.model.cfg.local_window)
@@ -2243,7 +2242,7 @@ class DecodeEngine:
 
     def mesh_desc(self) -> Optional[dict]:
         """{"shape": [...], "axes": [...]} for the engine's mesh (None =
-        single-device) — the schema serve_bench records under ``mesh``."""
+        single-device) — the ``mesh`` field of the serving summary."""
         if self.mesh is None:
             return None
         return {
@@ -2255,8 +2254,7 @@ class DecodeEngine:
         """Per-shard placement facts for the mesh-native engine.
 
         Reports, per weight/cache leaf and in aggregate, the bytes one
-        shard holds (``sharding.shard_shape``) next to the global bytes —
-        the per-shard HBM numbers the serve_bench sharded sweep records —
+        shard holds (``sharding.shard_shape``) next to the global bytes,
         plus which weight leaves ended up fully replicated (none should,
         for 2-D+ matmul weights on a model-axis mesh).  With
         ``include_hlo=True`` the decode executable is lowered + compiled

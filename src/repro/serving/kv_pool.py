@@ -144,7 +144,7 @@ class PagedKVPool:
         self.pending_copies: list[tuple[int, int]] = []
         self.evicted_pages = 0  # whole pages freed by window sliding
         self.cow_copies = 0  # copy-on-write page forks
-        # sync accounting (serve_bench host-overhead reporting)
+        # sync accounting (host-overhead reporting in the engine's stats)
         self.table_full_uploads = 0  # whole-table device uploads
         self.table_row_syncs = 0  # dirty rows scattered incrementally
         self.table_syncs = 0  # device_tables calls that moved any data

@@ -75,9 +75,10 @@ def make_train_step(
 
     def step(state: TrainState, batch: Any) -> tuple[TrainState, dict]:
         phase2 = getattr(state.opt, "phase2", jnp.zeros((), jnp.bool_))
-        mask, active, rstate = recipe.masks_for_step(
-            state.params, state.recipe, phase2
-        )
+        with jax.named_scope("nm_mask"):
+            mask, active, rstate = recipe.masks_for_step(
+                state.params, state.recipe, phase2
+            )
 
         def masked_loss(p):
             fp = recipe.forward_params(p, mask, active)
@@ -95,13 +96,13 @@ def make_train_step(
             grads = jax.lax.pmean(grads, axis_name)
             loss = jax.lax.pmean(loss, axis_name)
 
-        gnorm = global_norm(grads)
-        if grad_clip is not None:
-            scale = jnp.minimum(1.0, grad_clip / (gnorm + 1e-12))
-            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-
-        updates, ostate = opt.update(grads, state.opt, state.params)
-        params = apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            gnorm = global_norm(grads)
+            if grad_clip is not None:
+                scale = jnp.minimum(1.0, grad_clip / (gnorm + 1e-12))
+                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+            updates, ostate = opt.update(grads, state.opt, state.params)
+            params = apply_updates(state.params, updates)
         new_state = TrainState(
             params=params,
             opt=ostate,
@@ -125,6 +126,23 @@ def make_train_step(
     return step
 
 
+class TracedStep:
+    """A jitted train step whose every call runs inside a host span
+    ``train.step`` (``jax.profiler.StepTraceAnnotation``, numbered from 1),
+    so a profile places each step's dispatch on the device's clock.  With no
+    profiler session open the span costs about a microsecond."""
+
+    def __init__(self, fn: Callable):
+        self._fn = fn
+        self.lower = fn.lower
+        self._calls = 0
+
+    def __call__(self, state: TrainState, batch: Any) -> tuple[TrainState, dict]:
+        self._calls += 1
+        with jax.profiler.StepTraceAnnotation("train.step", step_num=self._calls):
+            return self._fn(state, batch)
+
+
 @dataclasses.dataclass
 class Trainer:
     """Fault-tolerant driver around ``make_train_step``."""
@@ -140,15 +158,17 @@ class Trainer:
 
     def __post_init__(self):
         self.opt = step_optimizer(self.step_cfg)
-        self._step = jax.jit(
-            make_train_step(
-                self.loss_fn,
-                self.recipe,
-                self.opt,
-                grad_clip=self.cfg.grad_clip,
-                compress_phase2=self.cfg.compress_phase2,
-            ),
-            donate_argnums=(0,) if self.cfg.donate else (),
+        self._step = TracedStep(
+            jax.jit(
+                make_train_step(
+                    self.loss_fn,
+                    self.recipe,
+                    self.opt,
+                    grad_clip=self.cfg.grad_clip,
+                    compress_phase2=self.cfg.compress_phase2,
+                ),
+                donate_argnums=(0,) if self.cfg.donate else (),
+            )
         )
 
     def init_state(self, params: Any, seed: int = 0) -> TrainState:
@@ -190,19 +210,27 @@ class Trainer:
         ``step_timeout``: straggler deadline in seconds; a step exceeding it
         is logged (on a real cluster the launcher uses this signal to evict
         the slow host and restart from the last checkpoint — the elastic
-        restore path exercised in tests)."""
+        restore path exercised in tests).
+
+        A logged step's ``step_time_s`` is the mean step time since the
+        host last waited for the device (the previous logged step or
+        checkpoint save): steps are dispatched ahead, so one step timed
+        alone would carry the steps still queued before it."""
         state, start = self.restore_or_init(params, seed)
         history: list[dict] = []
+        t_sync, since = time.perf_counter(), 0
         for step in range(start, self.cfg.total_steps):
             batch = next(self.data)
-            t0 = time.perf_counter()
             state, metrics = self._step(state, batch)
+            since += 1
             if self.cfg.log_every and (step % self.cfg.log_every == 0):
                 metrics = {
                     k: float(v) if jnp.ndim(v) == 0 else v for k, v in metrics.items()
                 }
                 metrics["step"] = step
-                dt = time.perf_counter() - t0
+                now = time.perf_counter()
+                dt = (now - t_sync) / since
+                t_sync, since = now, 0
                 metrics["step_time_s"] = dt
                 if step_timeout and dt > step_timeout:
                     metrics["straggler"] = True
@@ -215,6 +243,7 @@ class Trainer:
                 and step % self.cfg.ckpt_every == 0
             ):
                 self.checkpointer.save(step, state, {"step": step})
+                t_sync, since = time.perf_counter(), 0
             if self.eval_fn is not None and self.cfg.eval_every and step % self.cfg.eval_every == 0:
                 history.append({"step": step, **self.eval_fn(state.params, step)})
         if self.checkpointer is not None:

@@ -111,3 +111,30 @@ def test_straggler_deadline_flag():
     params = TASK.student_init(jax.random.PRNGKey(4))
     _, hist = tr.run(params, step_timeout=1e-9)  # everything is a straggler
     assert any(m.get("straggler") for m in hist)
+
+
+def test_logged_step_time_is_the_mean_since_the_last_sync(monkeypatch):
+    """Steps run ahead of the host, so a logged step's time is the host
+    time since the previous logged step over the steps in between."""
+    import types
+
+    import repro.train.loop as loop
+
+    tr = _make_trainer(None, 5)
+    tr.cfg = dataclasses.replace(tr.cfg, log_every=2)
+    clock = iter([0.0, 4.0, 10.0, 20.0])  # loop start, then steps 0, 2, 4
+    monkeypatch.setattr(loop, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(clock)))
+    _, hist = tr.run(TASK.student_init(jax.random.PRNGKey(5)))
+    assert [m["step"] for m in hist] == [0, 2, 4]
+    assert [m["step_time_s"] for m in hist] == [4.0, 3.0, 5.0]
+
+
+def test_train_step_keeps_lower_and_counts_its_calls():
+    tr = _make_trainer(None, 1)
+    state = tr.init_state(TASK.student_init(jax.random.PRNGKey(6)))
+    batch = next(tr.data)
+    assert tr._step.lower(state, batch).compile() is not None
+    state, m = tr._step(state, batch)
+    state, m = tr._step(state, next(tr.data))
+    assert tr._step._calls == 2 and np.isfinite(float(m["loss"]))
