@@ -8,6 +8,12 @@ weight stored ``(in_features, out_features)`` (the layout used throughout
 the same convention NVIDIA ASP uses for Sparse Tensor Cores, and the one our
 ``kernels/nm_spmm`` Pallas kernel consumes.
 
+:func:`nm_mask` ranks each element inside its group by elementwise compares
+against the other ``M - 1`` (:func:`_group_rank`), on the weight with the
+group axis split in place: one fused pass, with no sort, scatter or
+transpose, for any number of leading or trailing axes (the layer-stacked
+``(L, in, out)`` leaves the training step masks every step).
+
 The Pallas-fused version of :func:`nm_mask` lives in ``repro.kernels.nm_mask``;
 this module is the oracle (``kernels/ref.py`` re-exports from here) and the
 default on CPU.
@@ -50,6 +56,36 @@ def _move_group_axis_last(w: jnp.ndarray, axis: int) -> jnp.ndarray:
     return jnp.moveaxis(w, axis, -1)
 
 
+@partial(jax.jit, static_argnums=(1, 2))
+def _group_rank(w: jnp.ndarray, m: int, group_axis: int) -> jnp.ndarray:
+    """How many entries of its group of ``m`` along ``group_axis`` beat each
+    entry of ``w``: a larger ``|w|``, or an equal one at a lower index.
+
+    The axis is split in place to ``(..., G, m, ...)``, so the other axes keep
+    their layout, and ``m`` is static: the rank unrolls into ``m * (m - 1)``
+    elementwise compares that fuse into one pass over ``w`` (no sort, no
+    scatter, no transpose). ``rank < n`` is ``top_k``'s choice of ``n``.
+    """
+    axis = group_axis % w.ndim
+    if w.shape[axis] % m != 0:
+        raise ValueError(
+            f"axis {axis} of shape {w.shape} not divisible by group size {m}"
+        )
+    gshape = w.shape[:axis] + (w.shape[axis] // m, m) + w.shape[axis + 1:]
+    a = jnp.abs(w).reshape(gshape)
+    col = [jax.lax.index_in_dim(a, i, axis + 1, keepdims=False) for i in range(m)]
+
+    def beats(j, i):  # entry j of a group ranks above entry i
+        return (col[j] >= col[i]) if j < i else (col[j] > col[i])
+
+    zero = jnp.zeros(col[0].shape, jnp.int32)
+    rank = [
+        sum((beats(j, i).astype(jnp.int32) for j in range(m) if j != i), zero)
+        for i in range(m)
+    ]
+    return jnp.stack(rank, axis=axis + 1).reshape(w.shape)
+
+
 def nm_mask(
     w: jnp.ndarray,
     n: int,
@@ -59,25 +95,14 @@ def nm_mask(
     """Compute the binary N:M mask of ``w`` by magnitude.
 
     Returns a mask of ``w.dtype`` with exactly ``n`` ones per group of ``m``
-    consecutive elements along ``group_axis``. Ties are broken towards the
-    lower index (deterministic), matching ``jax.lax.top_k`` semantics.
+    consecutive elements along ``group_axis``: an element is kept when fewer
+    than ``n`` of its group beat it (:func:`_group_rank`). Ties are broken
+    towards the lower index (deterministic), matching ``jax.lax.top_k``
+    semantics.
     """
     if n == m:
         return jnp.ones_like(w)
-    axis = group_axis % w.ndim
-    if w.shape[axis] % m != 0:
-        raise ValueError(
-            f"axis {axis} of shape {w.shape} not divisible by group size {m}"
-        )
-    wt = _move_group_axis_last(w, axis)
-    gshape = wt.shape[:-1] + (wt.shape[-1] // m, m)
-    groups = jnp.abs(wt.reshape(gshape))
-    # top-n indices per group; scatter ones.
-    _, idx = jax.lax.top_k(groups, n)  # (..., G, n)
-    mask = jnp.zeros(gshape, dtype=w.dtype)
-    mask = jnp.put_along_axis(mask, idx, jnp.ones_like(idx, dtype=w.dtype), axis=-1, inplace=False)
-    mask = mask.reshape(wt.shape)
-    return jnp.moveaxis(mask, -1, axis)
+    return (_group_rank(w, m, group_axis) < n).astype(w.dtype)
 
 
 def nm_mask_and_apply(
@@ -141,21 +166,10 @@ def nm_mask_dynamic(
     """N:M mask where N is a *traced* scalar (needed by the Decaying-Mask
     recipe, whose N shrinks over training inside a jitted step).
 
-    Uses rank-within-group (double argsort) instead of ``top_k`` since the
-    latter needs a static k: ``mask[i] = rank(|w[i]|) < n``.
+    The same group rank as :func:`nm_mask`, compared with the traced ``n``:
+    ``mask[i] = rank(|w[i]|) < n``.
     """
-    axis = group_axis % w.ndim
-    if w.shape[axis] % m != 0:
-        raise ValueError(
-            f"axis {axis} of shape {w.shape} not divisible by group size {m}"
-        )
-    wt = _move_group_axis_last(w, axis)
-    gshape = wt.shape[:-1] + (wt.shape[-1] // m, m)
-    groups = jnp.abs(wt.reshape(gshape))
-    order = jnp.argsort(-groups, axis=-1, stable=True)
-    rank = jnp.argsort(order, axis=-1)
-    mask = (rank < n).astype(w.dtype).reshape(wt.shape)
-    return jnp.moveaxis(mask, -1, axis)
+    return (_group_rank(w, m, group_axis) < n).astype(w.dtype)
 
 
 def sparsity_fraction(mask: jnp.ndarray) -> jnp.ndarray:

@@ -2,10 +2,10 @@
 
 The per-step hot-spot of every STE-family recipe is re-deriving the N:M mask
 of every weight tensor from its current magnitudes (paper Eq. 8: Π_t is
-recomputed from w_t each step). The pure-jnp path (top_k + scatter) lowers to
-a sort plus several weight-sized HBM intermediates; this kernel streams each
-weight tile through VMEM exactly once and emits (Π⊙w, Π) with no extra HBM
-round-trips.
+recomputed from w_t each step). The pure-jnp path (in-group rank by
+elementwise compares, ``core.masking.nm_mask``) is one fused XLA pass over
+the weight of any rank; this kernel streams each 2-D weight tile through
+VMEM exactly once and emits (Π⊙w, Π) with no extra HBM round-trips.
 
 Algorithm (inside one (TR, TC) VMEM block, groups of M running down rows —
 axis 0 is the matmul reduction axis, matching ``core.masking``):

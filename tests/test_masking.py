@@ -3,6 +3,8 @@
 hypothesis is an optional dependency: without it the fixed-case tests still
 run and the property sweeps are skipped.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,6 +51,57 @@ def test_mask_indivisible_raises():
     w = jnp.zeros((10, 8))
     with pytest.raises(ValueError):
         mk.nm_mask(w, 2, 4, 0)
+
+
+def _topk_mask(w, n, m, axis):
+    """The N:M mask by ``top_k`` and a ``put_along_axis`` scatter: the oracle
+    that ``nm_mask``'s in-group comparisons must match bit for bit."""
+    wt = jnp.moveaxis(w, axis, -1)
+    groups = jnp.abs(wt.reshape(wt.shape[:-1] + (wt.shape[-1] // m, m)))
+    _, idx = jax.lax.top_k(groups, n)
+    ones = jnp.ones(idx.shape, w.dtype)
+    mask = jnp.put_along_axis(jnp.zeros_like(groups), idx, ones, axis=-1, inplace=False)
+    return jnp.moveaxis(mask.reshape(wt.shape), -1, axis)
+
+
+ORACLE_VALUES = {
+    "random": lambda w: w,
+    "ties": lambda w: jnp.round(w),  # a handful of levels: most groups tie
+    "zeros": jnp.zeros_like,
+    "signed_zeros": lambda w: jnp.where(w > 0, 0.0, -0.0).astype(w.dtype),
+}
+
+
+@pytest.mark.parametrize("values", sorted(ORACLE_VALUES))
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("layout", ["2d_axis0", "2d_axis1", "stacked_axis-2"])
+@pytest.mark.parametrize("n,m", [(1, 4), (2, 4), (2, 8), (4, 8)])
+def test_mask_matches_topk_oracle(n, m, layout, dtype, values):
+    shape, axis = {
+        "2d_axis0": ((6 * m, 40), 0),
+        "2d_axis1": ((40, 6 * m), 1),
+        "stacked_axis-2": ((3, 4 * m, 40), -2),
+    }[layout]
+    w = ORACLE_VALUES[values](
+        jax.random.normal(jax.random.PRNGKey(n * 10 + m), shape).astype(dtype)
+    )
+    mask = mk.nm_mask(w, n, m, axis)
+    oracle = _topk_mask(w, n, m, axis % w.ndim)
+    assert mask.dtype == w.dtype
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(oracle))
+
+
+def _hlo_ops(fn, w):
+    text = jax.jit(fn).lower(w).compile().as_text()
+    return set(re.findall(r"\s(sort|scatter)\(", text))
+
+
+def test_mask_compiles_without_sort_or_scatter():
+    """The stacked-leaf mask is elementwise compares: no sort, no scatter
+    (the oracle's HLO shows the pattern finds both when they are there)."""
+    w = jax.ShapeDtypeStruct((2, 64, 256), jnp.bfloat16)
+    assert _hlo_ops(lambda w: mk.nm_mask(w, 2, 4, -2), w) == set()
+    assert _hlo_ops(lambda w: _topk_mask(w, 2, 4, 1), w) == {"sort", "scatter"}
 
 
 @settings(max_examples=25, deadline=None)
