@@ -13,7 +13,7 @@ import json
 import time
 from typing import Optional
 
-from bench.harness import device, spec, trace
+from bench.harness import device, scopes, spec, trace
 
 clock = time.perf_counter
 TRACE_DIR = spec.BENCH_DIR / ".traces"
@@ -45,6 +45,9 @@ class Ctx:
     end: float
     trace: Optional[trace.Summary]
     extra: dict  # runner-specific numbers (the training rate)
+    # the program's scopes that the cell's metric files declare, each with
+    # the region it nests in (``scopes.declared``)
+    nested: dict = dataclasses.field(default_factory=dict)
 
 
 def emit(obj: dict) -> None:
@@ -56,9 +59,11 @@ def _metric(value: float, unit: str) -> dict:
 
 
 def _per_layer(cell: spec.Cell, ctx: Ctx) -> dict:
+    readers = [spec.metric_reader(m["name"], cell.root) for m in cell.per_layer]
+    ctx = dataclasses.replace(ctx, nested=scopes.declared(readers))
     out = {}
-    for m in cell.per_layer:
-        v = spec.metric_reader(m["name"], cell.root).read(ctx)
+    for m, reader in zip(cell.per_layer, readers):
+        v = reader.read(ctx)
         if v is not None:
             out[m["name"]] = _metric(float(v), m["unit"])
     return out

@@ -24,6 +24,13 @@ HLO ``op_name`` as ``args.tf_op``, a path such as
 ``region_ms`` folds ``autoswitch`` into ``optimizer``.  A run that was not
 traced, whose trace has no device operation (as on a CPU), or whose
 program emits no ``train.step`` span or known scope reads ``None``.
+
+The known scopes are ``SCOPES`` and those that the cell's metric files
+declare (``declared``): a metric file may set ``SCOPE = (name, parent)``,
+a scope of the program's and the region it nests in.  Each declared scope
+is a region of its own, so its operations leave the parent's region, and
+the regions still sum to busy; a declared scope that no operation carries
+reads ``None``.
 """
 from __future__ import annotations
 
@@ -64,6 +71,15 @@ class Profile:
     step_spans: list  # (start, dur) ns of each train.step in the window
     gaps: list  # the 10 longest (ns, train.step or idle) on the first device
     devices: int
+    nested: dict = dataclasses.field(default_factory=dict)  # declared
+
+    @property
+    def known(self) -> tuple:
+        return SCOPES + tuple(self.nested)
+
+    @property
+    def regions(self) -> tuple:
+        return REGIONS[:-1] + tuple(self.nested) + (UNSCOPED,)
 
     @property
     def steps(self) -> int:
@@ -76,14 +92,15 @@ class Profile:
         return ns / 1e6 / self.devices / self.steps
 
     def region_ms(self, region: str) -> float:
-        """Device self time per step in one region (``REGIONS``)."""
+        """Device self time per step in one region (``regions``)."""
         return self.per_step_ms(sum(
             o.self_ns for o in self.ops
             if PARENT.get(o.scope, o.scope) == region))
 
     def table(self) -> dict:
-        """``{scope: {pass: ms per step}}`` over ``SCOPES`` and unscoped."""
-        out = {s: dict.fromkeys(PASSES, 0.0) for s in SCOPES + (UNSCOPED,)}
+        """``{scope: {pass: ms per step}}`` over the known scopes and
+        unscoped."""
+        out = {s: dict.fromkeys(PASSES, 0.0) for s in self.known + (UNSCOPED,)}
         for o in self.ops:
             out[o.scope][o.phase] += self.per_step_ms(o.self_ns)
         return out
@@ -102,7 +119,32 @@ class Profile:
         return statistics.median(d for _, d in self.step_spans) / 1e6
 
 
-def classify(path: str) -> tuple[str, str]:
+def declared(readers) -> dict:
+    """``{scope: parent}`` that metric reader modules declare as
+    ``SCOPE = (scope, parent)``; the parent is a region, or a declared scope
+    that leads to one."""
+    out: dict = {}
+    for r in readers:
+        if not hasattr(r, "SCOPE"):
+            continue
+        name, parent = r.SCOPE
+        if name in SCOPES or name == UNSCOPED or out.get(name, parent) != parent:
+            raise ValueError(f"scope {name!r} in {parent!r} ({r.__name__}) is "
+                             "a known scope or declared twice")
+        out[name] = parent
+    for name in out:
+        up, seen = name, {name}
+        while up in out:
+            up = out[up]
+            if up in seen:
+                raise ValueError(f"scopes nest in a loop: {sorted(seen)}")
+            seen.add(up)
+        if up not in REGIONS[:-1]:
+            raise ValueError(f"scope {name!r} nests in {up!r}, no region")
+    return out
+
+
+def classify(path: str, known=SCOPES) -> tuple[str, str]:
     """``(scope, pass)`` of one operation from its ``tf_op`` path
     (``op_name``, in the export followed by ``:`` and the op type)."""
     path = path.split(";", 1)[0]
@@ -111,7 +153,7 @@ def classify(path: str) -> tuple[str, str]:
     scope = UNSCOPED
     for part in path.split("/"):
         name = _WRAPPER.sub("", part)
-        if name in SCOPES:
+        if name in known:
             scope = name
     if "rematted_computation" in path:
         phase = "recompute"
@@ -131,17 +173,20 @@ def find(directory: str) -> Optional[str]:
     return None
 
 
-def load(directory: str) -> Optional[Profile]:
+def load(directory: str, nested: Optional[dict] = None) -> Optional[Profile]:
     """The profile of the traced run whose directory is given, parsed once
-    per file; ``None`` where there is no export or no device operation."""
+    per file and set of ``nested`` scopes (``declared``); ``None`` where
+    there is no export or no device operation."""
     path = find(directory)
     if path is None:
         return None
-    return _parse(path, os.stat(path).st_mtime_ns)
+    return _parse(path, os.stat(path).st_mtime_ns,
+                  tuple((nested or {}).items()))
 
 
 @functools.lru_cache(maxsize=1)
-def _parse(path: str, _mtime_ns: int) -> Optional[Profile]:
+def _parse(path: str, _mtime_ns: int, nested: tuple) -> Optional[Profile]:
+    known = SCOPES + tuple(n for n, _ in nested)
     with gzip.open(path, "rt") as f:
         events = json.load(f)["traceEvents"]
     devices, xla_ops = {}, set()
@@ -175,7 +220,7 @@ def _parse(path: str, _mtime_ns: int) -> Optional[Profile]:
             continue
         s, end = max(s, t0), min(end, t1)
         args = e.get("args", {})
-        scope, phase = classify(args.get("tf_op", ""))
+        scope, phase = classify(args.get("tf_op", ""), known)
         dev = devices[e["pid"]]
         name = trace._op_name(args.get("long_name") or e["name"])
         by_dev.setdefault(dev, []).append(
@@ -196,15 +241,17 @@ def _parse(path: str, _mtime_ns: int) -> Optional[Profile]:
     return Profile(
         window_ns=t1 - t0, busy_ns=busy / n, ops=ops, step_spans=steps,
         gaps=[(d, trace._host_at(spans, (a + b) / 2)) for d, a, b in gaps],
-        devices=n)
+        devices=n, nested=dict(nested))
 
 
 def per_step(ctx, region: str) -> Optional[float]:
     """A region metric's reading for a run's ``Ctx``: device self time per
     step in ms, or ``None`` where the run was not traced or its program
-    names no scope or step."""
+    names no scope or step, or not the declared scope that is read."""
     p = _profile(ctx)
     if p is None or not p.scoped():
+        return None
+    if region in p.nested and all(o.scope != region for o in p.ops):
         return None
     return p.region_ms(region)
 
@@ -219,5 +266,5 @@ def _profile(ctx) -> Optional[Profile]:
 
     if ctx.trace is None:
         return None
-    p = load(str(cells.TRACE_DIR))
+    p = load(str(cells.TRACE_DIR), ctx.nested)
     return p if p is not None and p.steps else None

@@ -8,7 +8,10 @@ the usual initialisation's spread (``std = sqrt(2 / (fan_in + fan_out))``
 for matrices, 0.02 for the token embedding), drawn by exact arithmetic so
 that the program's and the reference's weights are the same bits on any
 backend; biases and norm parameters are drawn too (not zeros/ones), so the
-comparison with the reference covers them.
+comparison with the reference covers them.  A norm scale is drawn around a
+centre the caller gives: 1 where the norm multiplies by its scale, 0 where
+it multiplies by ``1 + scale``, so the effective scale is ``1 +- 0.02 sqrt 3``
+either way.
 """
 from __future__ import annotations
 
@@ -41,9 +44,10 @@ def _uniform(key, shape: tuple, scale: float) -> jax.Array:
     return i.astype(jnp.float32) * (scale / (1 << 23))
 
 
-def _draw(key, path: str, shape: tuple, dtype) -> jax.Array:
+def _draw(key, path: str, shape: tuple, dtype, centre: float) -> jax.Array:
     """One layer's (or an unstacked) leaf: uniform with the standard
-    deviation the usual initialisation gives."""
+    deviation the usual initialisation gives, a norm scale around
+    ``centre``."""
     name = path.rsplit("/", 1)[-1]
     if name == "tok_embed" or len(shape) == 1:
         std = 0.02  # the token embedding, biases and norm offsets
@@ -51,24 +55,25 @@ def _draw(key, path: str, shape: tuple, dtype) -> jax.Array:
         std = (2.0 / (shape[-2] + shape[-1])) ** 0.5
     x = _uniform(key, shape, std * 3.0 ** 0.5)
     if name == "norm_scale":
-        x = 1.0 + x
+        x = centre + x
     return x.astype(dtype)
 
 
 def leaf(key, path: str, shape: tuple, dtype, stacked: bool,
-         layer=None) -> jax.Array:
+         layer=None, centre: float = 1.0) -> jax.Array:
     """The values of one leaf; for a stacked leaf, ``layer`` picks one
     layer (``shape`` is then the per-layer shape), else all layers."""
     k = _leaf_key(key, path)
     if not stacked:
-        return _draw(k, path, tuple(shape), dtype)
+        return _draw(k, path, tuple(shape), dtype, centre)
     if layer is not None:
-        return _draw(jax.random.fold_in(k, layer), path, tuple(shape), dtype)
+        return _draw(jax.random.fold_in(k, layer), path, tuple(shape), dtype,
+                     centre)
     # layer by layer, each layer by the very call that makes it alone (no
     # vmap), so the stacked leaf and the reference's layers are the same
     # program for the random-bit generator on every backend
     return jnp.stack([_draw(jax.random.fold_in(k, l), path, tuple(shape[1:]),
-                            dtype) for l in range(shape[0])])
+                            dtype, centre) for l in range(shape[0])])
 
 
 def path_str(path) -> str:
@@ -83,11 +88,12 @@ def path_str(path) -> str:
     return "/".join(parts)
 
 
-def make_tree(shapes, key) -> dict:
+def make_tree(shapes, key, centre: float = 1.0) -> dict:
     """Fill a tree of ``ShapeDtypeStruct`` leaves; leaves under ``body``
-    carry a leading layer axis."""
+    carry a leading layer axis; norm scales are drawn around ``centre``."""
     def fill(path, s):
         p = path_str(path)
-        return leaf(key, p, s.shape, s.dtype, stacked=p.startswith("body/"))
+        return leaf(key, p, s.shape, s.dtype, stacked=p.startswith("body/"),
+                    centre=centre)
 
     return jax.tree_util.tree_map_with_path(fill, shapes)

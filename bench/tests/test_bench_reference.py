@@ -3,6 +3,8 @@ the seeded weights, and the STEP steps (two precondition steps, then one
 mask-learning step)."""
 from __future__ import annotations
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,3 +52,44 @@ def test_reference_stores_each_leaf_as_the_program_does():
     for p, x in jax.tree_util.tree_leaves_with_path(dense):
         np.testing.assert_array_equal(np.asarray(x, np.float32),
                                       ref[W.path_str(p)])
+
+
+def _crc(tree) -> int:
+    c = 0
+    for p, x in sorted(jax.tree_util.tree_leaves_with_path(tree),
+                       key=lambda t: W.path_str(t[0])):
+        c = zlib.crc32(np.asarray(x).tobytes(), c)
+    return c
+
+
+def test_the_layernorm_tree_is_the_bits_it_was():
+    # the CRC of the test configuration's tree as the generator made it
+    # before the norm scale's centre came from the builder
+    mdl = system.model(CFG)
+    assert _crc(system.dense_params(mdl, W.seed_key(4294967301))) == 712933969
+
+
+def test_expert_model_weights_are_the_same_made_whole_or_layer_by_layer():
+    """The latent-attention, routed-expert tree: the scan-stacked body
+    (``(L, E, in, out)`` expert stacks among it) drawn layer by layer,
+    the dense first layer ``head_0`` unstacked, and RMSNorm scales around
+    0, as the program's ``1 + scale`` wants them."""
+    cfg = spec.load_json(tiny.DATA / "configs" / "tiny-mla-moe.json")
+    mdl = system.model(cfg)
+    key = W.seed_key(4294967301)
+    whole = system.dense_params(mdl, key)
+    leaves = {W.path_str(p): x
+              for p, x in jax.tree_util.tree_leaves_with_path(whole)}
+    assert leaves["body/sb_0/moe/w_gate_e"].shape == (2, 8, 64, 32)
+    assert "head_0/mlp/w_gate" in leaves
+    for path, x in leaves.items():
+        if path.startswith("body/"):
+            for l in range(x.shape[0]):
+                one = W.leaf(key, path, x.shape[1:], x.dtype, True, layer=l,
+                             centre=0.0)
+                np.testing.assert_array_equal(x[l], one)
+        else:
+            np.testing.assert_array_equal(
+                x, W.leaf(key, path, x.shape, x.dtype, False, centre=0.0))
+        if path.endswith("norm_scale"):
+            assert float(jnp.max(jnp.abs(x))) <= 0.02 * 3 ** 0.5

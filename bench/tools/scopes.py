@@ -3,8 +3,11 @@
     python3 bench/tools/scopes.py [DIR]
 
 ``DIR`` is a traced run's profile directory (default ``bench/.traces``,
-where the last traced run left it).  Prints, per step in ms: each scope
-by pass (forward, backward, remat recompute), each region's total and its
+where the last traced run left it).  The known scopes are the program's
+and those that the metric files ``BENCHMARK.json`` names declare, each
+nested in its region.  Prints each declared scope with its region, then,
+per step in ms: each scope by pass (forward, backward, remat recompute),
+each region's total (a declared scope is a region of its own) and its
 longest operations, the longest operations by name split by scope, the ten
 longest idle gaps named by the program span open at their middle
 (``train.step`` or ``idle``), the median host time of a ``train.step``
@@ -23,14 +26,18 @@ sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 
 def main() -> int:
-    from bench.harness import cells, scopes, trace
+    from bench.harness import cells, scopes, spec, trace
 
     directory = sys.argv[1] if len(sys.argv) > 1 else str(cells.TRACE_DIR)
-    p = scopes.load(directory)
+    nested = scopes.declared(spec.metric_reader(m["name"])
+                             for m in spec.load_benchmark()["per_layer"])
+    p = scopes.load(directory, nested)
     if p is None or not p.steps:
         print(f"no device operations or {scopes.STEP_SPAN} spans under "
               f"{directory}", file=sys.stderr)
         return 1
+    for name, parent in nested.items():
+        print(f"scope {name} nested in {parent}")
     per = lambda ns: p.per_step_ms(ns)
     print(f"steps {p.steps}  devices {p.devices}  window {p.window_ns / 1e6:.3f} ms"
           f"  busy {p.busy_ns / 1e6:.3f} ms ({per(p.busy_ns * p.devices):.3f} per step)"
@@ -48,7 +55,7 @@ def main() -> int:
               + f"{sum(row.values()):>12.3f}")
     print("\nregion (ms per step, share of busy), longest operations")
     busy = per(p.busy_ns * p.devices)
-    for region in scopes.REGIONS:
+    for region in p.regions:
         ms = p.region_ms(region)
         tops = ", ".join(f"{n} {t:.3f}" for n, t in p.top_ops(region))
         print(f"{region:<12}{ms:>10.3f} {100 * ms / busy:6.2f}%  {tops}")
